@@ -11,10 +11,7 @@ from repro.kernels.ssd import ssd_chunked_pallas as _ssd
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def attention(q, k, v, *, causal=True, window=0, softcap=0.0,

@@ -10,8 +10,9 @@ Within a chunk the computation is three MXU matmuls on (chunk x d_state) /
   y      = scores @ Xd  +  (C . exp(cs)) @ state^T
   state  = exp(cs_last) * state + Xd^T (B . decay_states)
 
-The decay quantities come from a cumulative sum of dt*A over the chunk —
-small VPU work. B/C are single-group (shared across heads): their BlockSpec
+The decay quantities come from a cumulative sum of dt*A over the chunk,
+taken as two small lower-triangular matmuls because Mosaic cannot lower
+``cumsum``. B/C are single-group (shared across heads): their BlockSpec
 index_map drops the head index, so no materialized per-head broadcast.
 """
 from __future__ import annotations
@@ -22,10 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
 
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fs_ref, state_ref,
@@ -38,20 +35,34 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fs_ref, state_ref,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[...].astype(jnp.float32)          # (chunk, p)
-    dt = dt_ref[...].astype(jnp.float32)[:, 0]  # (chunk,)
+    dt = dt_ref[...].astype(jnp.float32)        # (chunk, 1)
     A = a_ref[0, 0]                             # scalar
     B = b_ref[...].astype(jnp.float32)          # (chunk, n)
     C = c_ref[...].astype(jnp.float32)          # (chunk, n)
 
-    dA = dt * A                                  # (chunk,) negative
-    cs = jnp.cumsum(dA)                          # (chunk,)
-    Xd = x * dt[:, None]                         # (chunk, p)
+    dA = dt * A                                  # (chunk, 1) negative
+    Xd = x * dt                                  # (chunk, p)
 
-    # intra-chunk: decay-masked scores
+    # Prefix sum of dA as lower-triangular matmuls on the MXU (Mosaic has no
+    # cumsum). D[i, k] = dA_i; tri[i, j] = (i >= j).
     li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    seg = cs[:, None] - cs[None, :]              # cs_i - cs_j
-    decay = jnp.where(li >= lj, jnp.exp(seg), 0.0)
+    causal = li >= lj
+    tri = causal.astype(jnp.float32)
+    D = jnp.broadcast_to(dA, (chunk, chunk))
+    hi = jax.lax.Precision.HIGHEST
+    cs_rows = jax.lax.dot_general(tri, D, (((1,), (0,)), ((), ())),
+                                  precision=hi,
+                                  preferred_element_type=jnp.float32)
+    cs_cols = jax.lax.dot_general(D, tri, (((0,), (1,)), ((), ())),
+                                  precision=hi,
+                                  preferred_element_type=jnp.float32)
+    cs = cs_rows[:, :1]                          # (chunk, 1): cs_i
+    cs_last = jnp.sum(dA, axis=0, keepdims=True)  # (1, 1)
+
+    # intra-chunk: decay-masked scores
+    seg = cs_rows - cs_cols                      # cs_i - cs_j
+    decay = jnp.where(causal, jnp.exp(seg), 0.0)
     scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
     y = jax.lax.dot_general(scores * decay, Xd, (((1,), (0,)), ((), ())),
@@ -59,17 +70,17 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fs_ref, state_ref,
 
     # inter-chunk: contribution of the carried state
     state = state_ref[...]                       # (p, n) fp32
-    Cd = C * jnp.exp(cs)[:, None]                # (chunk, n)
+    Cd = C * jnp.exp(cs)                         # (chunk, n)
     y = y + jax.lax.dot_general(Cd, state, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
     y_ref[...] = y.astype(y_ref.dtype)
 
     # state update: S' = exp(cs_last) S + Xd^T (B . decay_states)
-    decay_states = jnp.exp(cs[-1] - cs)[:, None]  # (chunk, 1)
+    decay_states = jnp.exp(cs_last - cs)         # (chunk, 1)
     upd = jax.lax.dot_general(Xd, B * decay_states,
                               (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)  # (p, n)
-    state_ref[...] = state * jnp.exp(cs[-1]) + upd
+    state_ref[...] = state * jnp.exp(cs_last) + upd
 
     @pl.when(ci == nc - 1)
     def _fini():
@@ -112,7 +123,7 @@ def ssd_chunked_pallas(x, dt, A, B, C, chunk: int = 128, interpret=False):
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xt, dtt, At, B, C)

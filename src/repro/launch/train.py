@@ -23,18 +23,27 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
+from repro.configs.base import ShapeConfig
 from repro.core import TaskRuntime, Tracer
 from repro.data import DataPipeline, TokenSource
 from repro.data.pipeline import batch_addr
 from repro.dist.partitioning import make_sharder
 from repro.ft import HeartbeatMonitor, StragglerMitigator
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_host_mesh
-from repro.launch.steps import (TrainConfig, init_train_state,
+from repro.launch.steps import (TrainConfig, abstract_train_state,
+                                batch_spec, init_train_state,
                                 make_train_step)
 from repro.optim import AdamWConfig
+
+
+def _shardings(abstract):
+    """The sharding tree of a tree of sharded ShapeDtypeStructs."""
+    return jax.tree_util.tree_map(lambda a: a.sharding, abstract)
 
 
 class TrainEngine:
@@ -50,9 +59,24 @@ class TrainEngine:
         tc = TrainConfig(microbatches=microbatches,
                          optimizer=opt or AdamWConfig(lr=1e-3, warmup_steps=5))
         self.tc = tc
+        # On a mesh the state is built directly in its sharded layout (FSDP:
+        # weights and AdamW moments split over "data") and the step keeps
+        # it there; without one, everything lives on the default device.
+        init_kw, step_kw, self._batch_sh = {}, {}, None
+        if mesh is not None:
+            state_sh = _shardings(abstract_train_state(cfg, self.sh))
+            self._batch_sh = _shardings(batch_spec(
+                cfg, ShapeConfig("train", seq_len, batch_size, "train"),
+                self.sh))
+            init_kw = {"out_shardings": state_sh}
+            step_kw = {"in_shardings": (state_sh, self._batch_sh),
+                       "out_shardings": (state_sh,
+                                         NamedSharding(mesh, P()))}
         self.step_fn = jax.jit(make_train_step(cfg, self.sh, tc),
-                               donate_argnums=(0,))
-        self.state = init_train_state(cfg, jax.random.PRNGKey(seed), tc.optimizer)
+                               donate_argnums=(0,), **step_kw)
+        self.state = jax.jit(init_train_state, static_argnums=(0, 2),
+                             **init_kw)(cfg, jax.random.PRNGKey(seed),
+                                        tc.optimizer)
         frames_dim = cfg.d_model if cfg.family == "encdec" else None
         self.pipe = DataPipeline(
             self.rt, TokenSource(cfg.vocab_size, seed=seed), batch_size,
@@ -68,7 +92,10 @@ class TrainEngine:
 
     # ------------------------------------------------------------- steps
     def _device_batch(self, raw):
-        return {k: jnp.asarray(v) for k, v in raw.items()}
+        if self._batch_sh is None:
+            return {k: jnp.asarray(v) for k, v in raw.items()}
+        return {k: jax.device_put(v, self._batch_sh[k])
+                for k, v in raw.items()}
 
     def run(self, n_steps: int, log_every: int = 10, inject_failure_at=None):
         s0 = int(self.state["step"])
@@ -124,7 +151,8 @@ class TrainEngine:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True)
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
@@ -133,6 +161,7 @@ def main():
     ap.add_argument("--trace-dir", default=None)
     args = ap.parse_args()
 
+    setup_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     tracer = Tracer(enabled=bool(args.trace_dir), out_dir=args.trace_dir)
     mesh = make_host_mesh()

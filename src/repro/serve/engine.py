@@ -39,13 +39,14 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import threading
 import time
 from typing import Callable, Optional
 
 import numpy as np
 
-from repro.models.common import NULL_SHARDER
+from repro.models.common import NULL_SHARDER, cast_params, dtype_of
 
 
 @dataclasses.dataclass
@@ -506,7 +507,14 @@ class ServeEngine(EngineCore):
     """The jax-model engine: EngineCore + prefill forward, batched decode
     and the KV-cache splice. Single-runtime deployments use this directly;
     the sharded router drives one model engine (or simulated core) per
-    shard."""
+    shard.
+
+    The weights are held in ``cfg.dtype`` (cast once here, so the per-call
+    cast in ``forward`` is a no-op) and reach the jitted decode as an
+    argument: a closed-over array would be baked into the program as a
+    constant. A step whose logits are not finite raises
+    ``FloatingPointError``, which cancels the engine and surfaces from
+    ``stop()``."""
 
     def __init__(self, cfg, params, runtime, *, n_slots: int = 4,
                  max_seq: int = 256, sharder=NULL_SHARDER, greedy=True,
@@ -517,11 +525,13 @@ class ServeEngine(EngineCore):
 
         from repro.models import api as mapi
         self.cfg = cfg
-        self.params = params
+        self.params = cast_params(params, dtype_of(cfg))
         self.sh = sharder
         # batched caches: one cache tree with batch dim = n_slots
         self.cache = mapi.init_cache(cfg, n_slots, max_seq)
-        self._decode_fn = jax.jit(self._decode_batch)
+        self._decode_fn = jax.jit(
+            functools.partial(_decode_batch, cfg, sharder),
+            donate_argnums=(1,))
 
     # ---------------------------------------------------------- model ops
     def _prefill_one(self, tokens: np.ndarray):
@@ -532,18 +542,10 @@ class ServeEngine(EngineCore):
         batch = {"tokens": jnp.asarray(tokens)[None, :]}
         logits, _, cache = mapi.forward(self.cfg, self.params, batch, self.sh,
                                         mode="prefill")
-        first = int(jnp.argmax(logits[0, -1]))
-        return first, cache
-
-    def _decode_batch(self, cache, tokens, pos):
-        import jax.numpy as jnp
-
-        from repro.models import api as mapi
-        batch = {"tokens": tokens}
-        logits, _, new_cache = mapi.forward(
-            self.cfg, self.params, batch, self.sh, mode="decode",
-            cache=cache, cache_pos=pos)
-        return jnp.argmax(logits[:, -1, :], axis=-1), new_cache
+        last = logits[0, -1]
+        if not bool(jnp.all(jnp.isfinite(last))):
+            raise FloatingPointError("prefill produced non-finite logits")
+        return int(jnp.argmax(last)), cache
 
     # ---------------------------------------------------------- core hooks
     def _prefill_exec(self, req: Request, slot: int) -> int:
@@ -573,6 +575,25 @@ class ServeEngine(EngineCore):
             toks[i, 0] = self.active[i].tokens[-1]
         # per-slot cache positions (continuous batching): idle slots
         # write harmlessly into their own stale position
-        nxt, self.cache = self._decode_fn(self.cache, jnp.asarray(toks),
-                                          jnp.asarray(self.pos))
+        nxt, finite, self.cache = self._decode_fn(
+            self.params, self.cache, jnp.asarray(toks), jnp.asarray(self.pos))
+        finite = np.asarray(finite)
+        bad = [i for i in live if not finite[i]]
+        if bad:
+            raise FloatingPointError(
+                f"decode produced non-finite logits in slots {bad}")
         return np.asarray(nxt)
+
+
+def _decode_batch(cfg, sh, params, cache, tokens, pos):
+    """One batched decode step -> (greedy next token, logits finite, cache)
+    per slot. Module-level so that jit sees every array as an argument."""
+    import jax.numpy as jnp
+
+    from repro.models import api as mapi
+    logits, _, new_cache = mapi.forward(cfg, params, {"tokens": tokens}, sh,
+                                        mode="decode", cache=cache,
+                                        cache_pos=pos)
+    last = logits[:, -1, :]
+    return (jnp.argmax(last, axis=-1), jnp.all(jnp.isfinite(last), axis=-1),
+            new_cache)

@@ -13,7 +13,7 @@ from repro.data.pipeline import TokenSource
 from repro.launch.train import TrainEngine
 from repro.models import init_params
 from repro.optim import AdamWConfig
-from repro.serve import ServeEngine
+from repro.serve import Request, ServeEngine
 
 
 class PatternSource(TokenSource):
@@ -174,3 +174,54 @@ def test_serving_matches_sequential_decode():
     rt.barrier(timeout=30)
     rt.shutdown()
     assert r.tokens[:4] == ref[:4] if len(r.tokens) >= 4 else False
+
+
+def test_serving_decode_takes_weights_as_arguments():
+    """The jitted decode gets the weights as arguments (a closed-over array
+    would be lowered as a constant: at full width every weight would sit
+    inside the program), donates the cache, and the engine holds its
+    weights in cfg.dtype."""
+    cfg = get_config("qwen3-1.7b", smoke=True)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    rt = TaskRuntime(n_workers=1).start()
+    eng = ServeEngine(cfg, params, rt, n_slots=2, max_seq=32)
+    assert {x.dtype for x in jax.tree_util.tree_leaves(eng.params)} == \
+        {jnp.dtype(cfg.dtype)}
+    toks = jnp.zeros((2, 1), jnp.int32)
+    pos = jnp.zeros((2,), jnp.int32)
+    lowered = eng._decode_fn.lower(eng.params, eng.cache, toks, pos)
+    p_info, c_info, _, _ = lowered.args_info[0]
+    assert len(jax.tree_util.tree_leaves(p_info)) == \
+        len(jax.tree_util.tree_leaves(eng.params))
+    assert not any(a.donated for a in jax.tree_util.tree_leaves(p_info))
+    assert all(a.donated for a in jax.tree_util.tree_leaves(c_info))
+    consts = [ln for ln in lowered.as_text().splitlines()
+              if "stablehlo.constant" in ln]
+    biggest = max(map(len, consts), default=0)
+    assert biggest < 4096, f"an array is baked into the decode ({biggest} B)"
+    rt.shutdown()
+
+
+def test_serving_non_finite_logits_fail_loudly():
+    """Greedy argmax of NaN logits would quietly emit token 0: the engine
+    raises instead, the group cancels, waiters are released and stop()
+    re-raises — in prefill and in the batched decode."""
+    cfg = get_config("qwen3-1.7b", smoke=True)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    params["final_norm"]["scale"] = params["final_norm"]["scale"] * jnp.nan
+    rt = TaskRuntime(n_workers=2).start()
+    eng = ServeEngine(cfg, params, rt, n_slots=2, max_seq=32).start()
+    req = eng.submit(np.arange(4), max_new_tokens=3)
+    assert eng.wait(req, timeout=60), "client hung after non-finite prefill"
+    assert eng.group.cancelled
+    with pytest.raises(FloatingPointError, match="prefill"):
+        eng.stop()
+    rt.barrier(timeout=60)
+    with pytest.raises(FloatingPointError):
+        rt.shutdown()
+
+    eng = ServeEngine(cfg, params, TaskRuntime(n_workers=1), n_slots=2,
+                      max_seq=32)
+    eng.active[1] = Request(np.arange(4, dtype=np.int32), tokens=[1])
+    with pytest.raises(FloatingPointError, match=r"slots \[1\]"):
+        eng._decode_exec([1])
