@@ -20,8 +20,9 @@ rule catalog with the incident history lives in docs/SANITIZER.md):
 ``event-catalog``
     ``tracer.event(name, ...)`` names must be string literals present in
     the ``EVENTS`` catalog of ``core/instrument.py`` (or registered via
-    ``register_event``). Ad-hoc names serialize as event id 0 and make
-    the binary trace unparseable.
+    ``register_event``), and so must the begin and end events of every
+    ``tracer.span(name, ...)``. Ad-hoc names serialize as event id 0 and
+    make the binary trace unparseable.
 
 ``shared-random``
     No module-level ``random.*`` calls in ``core/`` worker code: the
@@ -46,6 +47,8 @@ import ast
 import os
 import re
 from typing import Iterable, Optional
+
+from repro.core.instrument import span_events
 
 RULES = {
     "waitfree-blocking": "blocking/spinning call inside a wait-free ASM "
@@ -196,20 +199,22 @@ class _FileLinter(ast.NodeVisitor):
                       "from worker code; use a per-worker "
                       "random.Random(seed)")
         # event-catalog
-        if isinstance(fn, ast.Attribute) and fn.attr == "event" and \
-                node.args:
+        if isinstance(fn, ast.Attribute) and fn.attr in ("event", "span") \
+                and node.args:
             name = node.args[0]
             if isinstance(name, ast.Constant) and isinstance(name.value,
                                                              str):
-                if name.value not in self.catalog and \
-                        name.value not in self.registered:
-                    self.emit(node, "event-catalog",
-                              f"event name {name.value!r} is not in "
-                              "core/instrument.py EVENTS (id 0 in the "
-                              "binary stream)")
+                names = span_events(name.value) if fn.attr == "span" \
+                    else (name.value,)
+                for n in names:
+                    if n not in self.catalog and n not in self.registered:
+                        self.emit(node, "event-catalog",
+                                  f"event name {n!r} is not in "
+                                  "core/instrument.py EVENTS (id 0 in the "
+                                  "binary stream)")
             else:
                 self.emit(node, "event-catalog",
-                          "non-literal trace event name cannot be "
+                          f"non-literal trace {fn.attr} name cannot be "
                           "checked against the catalog")
         self.generic_visit(node)
 

@@ -6,10 +6,11 @@ ordering the per-core buffers have natively in C). Buffers flush to one
 binary file per worker — a time-ordered event subset, CTF's layout — plus a
 JSON metadata file mapping event ids to names (the CTF metadata analogue).
 
-Kernel-event correlation (perf_event_open) has no portable Python analogue;
-we record OS noise instead via involuntary context-switch counters sampled
-around task execution (resource.getrusage(RUSAGE_THREAD)), giving the same
-"runtime + OS" combined view the paper uses in §6.4.
+Spans (``Tracer.span``) are a pair of catalog events, begin and end, with
+the same id. A tracer built with ``annotate=True`` also opens a
+``jax.profiler.TraceAnnotation`` per span, so the span lands in a profiler
+trace on the device trace's clock, where a device-idle gap can be put down
+to what the program was doing.
 """
 from __future__ import annotations
 
@@ -25,21 +26,18 @@ _REC = struct.Struct("<qii")  # ts_ns, event_id, arg
 EVENTS = {
     "task.create": 1,
     "task.ready": 2,
-    "task.start": 3,
+    "task.start": 3,        # span "task": one task body (arg: task id)
     "task.end": 4,
-    "dep.register": 5,
     "dep.unregister": 6,
     "sched.add": 7,
-    "sched.get": 8,
     "sched.delegated": 9,
     "sched.served": 10,
     "worker.idle": 11,
     "worker.park": 12,
-    "os.ctxswitch": 13,
     "ckpt.begin": 14,
     "ckpt.end": 15,
     "data.prefetch": 16,
-    "step.begin": 17,
+    "step.begin": 17,       # span "step" (arg: step)
     "step.end": 18,
     "worker.wake": 19,      # single-wake delivered to a parked worker
     "task.cancel": 20,      # group-cancelled task dropped (spawn or dequeue)
@@ -73,7 +71,41 @@ EVENTS = {
                             # (arg: drained task count moved across)
     "tune.knob": 43,        # runtime knob adjusted (park bounds, wake
                             # fan-out, EWMA mult); arg: KNOB_IDS code
+    # EngineCore spans (arg: the request id for prefill; the others take
+    # the id of the task span they open in, the decode task's)
+    "serve.prefill.begin": 44,  # _prefill_exec of one request
+    "serve.prefill.end": 45,
+    "serve.decode.begin": 46,   # _decode_exec of one batched iteration
+    "serve.decode.end": 47,
+    "serve.emit.begin": 48,     # per-slot tokens, callbacks and retires
+    "serve.emit.end": 49,
+    "serve.admit.begin": 50,    # queued requests moved into free slots
+    "serve.admit.end": 51,
+    "serve.idle.begin": 52,     # the decode loop's backoff with no slot live
+    "serve.idle.end": 53,
+    # ServeEngine child spans (same ids as their parent)
+    "serve.prefill.forward.begin": 54,  # the eager forward
+    "serve.prefill.forward.end": 55,
+    "serve.prefill.sync.begin": 56,     # finite check and argmax readback
+    "serve.prefill.sync.end": 57,
+    "serve.prefill.splice.begin": 58,   # the slot's cache splice
+    "serve.prefill.splice.end": 59,
+    "serve.decode.inputs.begin": 60,    # token and position arrays
+    "serve.decode.inputs.end": 61,
+    "serve.decode.launch.begin": 62,    # the jitted step's call
+    "serve.decode.launch.end": 63,
+    "serve.decode.sync.begin": 64,      # finite flags and tokens read back
+    "serve.decode.sync.end": 65,
 }
+
+# A span named X records the catalog events "X.begin" and "X.end"; the task
+# body keeps the runtime's older pair.
+_SPAN_ALIASES = {"task": ("task.start", "task.end")}
+
+
+def span_events(name: str) -> tuple:
+    """The (begin, end) catalog event names of span ``name``."""
+    return _SPAN_ALIASES.get(name) or (name + ".begin", name + ".end")
 
 
 def register_event(name: str) -> int:
@@ -91,12 +123,13 @@ def register_event(name: str) -> int:
 
 
 class _WorkerBuffer:
-    __slots__ = ("records", "capacity", "dropped")
+    __slots__ = ("records", "capacity", "dropped", "open_ids")
 
     def __init__(self, capacity: int):
         self.records: list = []
         self.capacity = capacity
         self.dropped = 0
+        self.open_ids: list = []  # ids of this thread's open spans
 
     def append(self, rec):
         if len(self.records) < self.capacity:
@@ -105,17 +138,59 @@ class _WorkerBuffer:
             self.dropped += 1
 
 
+class _NoSpan:
+    """The span a disabled tracer hands out: one shared, stateless object."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _Span:
+    __slots__ = ("buf", "ids", "arg", "ann")
+
+    def __init__(self, buf, ids, arg, ann):
+        self.buf, self.ids, self.arg, self.ann = buf, ids, arg, ann
+
+    def __enter__(self):
+        if self.ann is not None:
+            self.ann.__enter__()
+        self.buf.open_ids.append(self.arg)
+        self.buf.append((time.monotonic_ns(), self.ids[0], self.arg))
+        return self
+
+    def __exit__(self, *exc):
+        self.buf.append((time.monotonic_ns(), self.ids[1], self.arg))
+        self.buf.open_ids.pop()
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        return False
+
+
 class Tracer:
-    """enabled=False costs a single attribute check per event call."""
+    """enabled=False costs a single attribute check per event or span call.
+
+    ``annotate=True`` mirrors each span into the JAX profiler's trace (jax
+    is imported on the first annotated span, so ``core/`` runs without it).
+    Records stay in memory until ``flush()``; ``spans()`` and ``events()``
+    read them back on the host's monotonic clock."""
 
     def __init__(self, enabled: bool = False, capacity_per_worker: int = 1 << 16,
-                 out_dir: Optional[str] = None):
+                 out_dir: Optional[str] = None, annotate: bool = False):
         self.enabled = enabled
+        self.annotate = annotate
         self.capacity = capacity_per_worker
         self.out_dir = out_dir
         self._tls = threading.local()
         self._buffers: list[tuple[int, _WorkerBuffer]] = []
         self._buffers_lock = threading.Lock()
+        self._span_ids: dict = {}
 
     def _buf(self) -> _WorkerBuffer:
         b = getattr(self._tls, "buf", None)
@@ -126,9 +201,8 @@ class Tracer:
                 self._buffers.append((threading.get_ident(), b))
         return b
 
-    def event(self, name: str, arg: int = 0):
-        if not self.enabled:
-            return
+    @staticmethod
+    def _eid(name: str) -> int:
         eid = EVENTS.get(name)
         if eid is None:
             # an unregistered name would serialize as id 0 and be
@@ -136,7 +210,71 @@ class Tracer:
             raise ValueError(
                 f"unregistered trace event {name!r}: add it to "
                 "repro.core.instrument.EVENTS or call register_event()")
-        self._buf().append((time.monotonic_ns(), eid, int(arg)))
+        return eid
+
+    def event(self, name: str, arg: int = 0):
+        if not self.enabled:
+            return
+        self._buf().append((time.monotonic_ns(), self._eid(name), int(arg)))
+
+    def span(self, name: str, id: Optional[int] = None, label: str = ""):
+        """Context manager around one stretch of work: begin and end records
+        with ``id`` as their arg (by default the id of the innermost span
+        open on this thread, else 0), and, when annotating, a
+        TraceAnnotation ``name`` carrying ``id`` (and ``label``, where
+        given)."""
+        if not self.enabled:
+            return _NO_SPAN
+        ids = self._span_ids.get(name)
+        if ids is None:
+            ids = self._span_ids[name] = tuple(
+                self._eid(e) for e in span_events(name))
+        buf = self._buf()
+        if id is None:
+            id = buf.open_ids[-1] if buf.open_ids else 0
+        ann = None
+        if self.annotate:
+            from jax.profiler import TraceAnnotation
+            ann = TraceAnnotation(name, id=id, label=label) if label \
+                else TraceAnnotation(name, id=id)
+        return _Span(buf, ids, int(id), ann)
+
+    # ---------------------------------------------------------------- read
+    def _records(self) -> list:
+        with self._buffers_lock:
+            return [buf.records for _, buf in self._buffers]
+
+    def events(self, name: str) -> list:
+        """[(ts_ns, arg)] of every record of catalog event ``name``."""
+        eid = self._eid(name)
+        return sorted((ts, arg) for recs in self._records()
+                      for ts, e, arg in list(recs) if e == eid)
+
+    def spans(self, name: str) -> list:
+        """[(t0_ns, t1_ns, id)] of every closed span ``name``, by start.
+        Begin and end pair up per thread, innermost first."""
+        begin, end = (self._eid(e) for e in span_events(name))
+        out = []
+        for recs in self._records():
+            open_: list = []
+            for ts, e, arg in list(recs):
+                if e == begin:
+                    open_.append(ts)
+                elif e == end and open_:
+                    out.append((open_.pop(), ts, arg))
+        return sorted(out)
+
+    def dropped(self) -> int:
+        """Records refused by full buffers since the last ``clear()``."""
+        with self._buffers_lock:
+            return sum(buf.dropped for _, buf in self._buffers)
+
+    def clear(self) -> None:
+        """Drop every record so far (such as a warm-up's)."""
+        with self._buffers_lock:
+            for _, buf in self._buffers:
+                buf.records.clear()
+                buf.dropped = 0
 
     # ---------------------------------------------------------------- dump
     def flush(self, out_dir: Optional[str] = None) -> Optional[str]:
@@ -272,11 +410,3 @@ class CounterPlane:
         out["ewma_task_sq"] = ewma_sq
         return out
 
-
-def os_noise_sample() -> int:
-    """Involuntary context switches for the calling thread (OS noise probe)."""
-    try:
-        import resource
-        return resource.getrusage(resource.RUSAGE_THREAD).ru_nivcsw
-    except Exception:
-        return 0

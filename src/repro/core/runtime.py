@@ -898,20 +898,21 @@ class TaskRuntime:
             task.skip()
         else:
             _current_task.t = task
-            task.start_ns = time.monotonic_ns()
-            self.tracer.event("task.start", task.task_id)
-            if san is not None:
-                # pass the epoch THIS dequeue decided on: a cancel landing
-                # after the check above legitimately overlaps the body
-                san.on_start(task, wid, group_epoch=observed_epoch)
-            task.run()
-            task.end_ns = time.monotonic_ns()
-            self.counters.w(wid).on_task(task.end_ns - task.start_ns)
-            if san is not None:
-                # before unregister: successors join this task's clock via
-                # the completion messages, which need the end tick in place
-                san.on_end(task)
-            self.tracer.event("task.end", task.task_id)
+            with self.tracer.span("task", task.task_id, task.name):
+                task.start_ns = time.monotonic_ns()
+                if san is not None:
+                    # pass the epoch THIS dequeue decided on: a cancel
+                    # landing after the check above legitimately overlaps
+                    # the body
+                    san.on_start(task, wid, group_epoch=observed_epoch)
+                task.run()
+                task.end_ns = time.monotonic_ns()
+                self.counters.w(wid).on_task(task.end_ns - task.start_ns)
+                if san is not None:
+                    # before unregister: successors join this task's clock
+                    # via the completion messages, which need the end tick
+                    # in place
+                    san.on_end(task)
             _current_task.t = None
         if not self._defer_unregister:
             # wait-free deps: TASK_DONE must flow at body completion; the

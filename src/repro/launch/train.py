@@ -106,9 +106,8 @@ class TrainEngine:
             batch = self._device_batch(raw)
 
             def do_step(batch=batch):
-                self.rt.tracer.event("step.begin", s)
-                self.state, metrics = self.step_fn(self.state, batch)
-                self.rt.tracer.event("step.end", s)
+                with self.rt.tracer.span("step", s):
+                    self.state, metrics = self.step_fn(self.state, batch)
                 return {k: float(v) for k, v in metrics.items()}
 
             t = self.rt.spawn(do_step, name=f"step:{s}",

@@ -62,7 +62,11 @@ class Request:
     key: Optional[str] = None       # affinity key (session / prefix-cache)
     hslot: Optional[int] = None     # affinity_hash(key) when key is set
     shard_id: Optional[int] = None  # shard that admitted the request
+    # host monotonic stamps, always on: submitted, popped into a slot,
+    # prefill body entered, retired
     submit_ns: int = 0
+    slot_ns: int = 0
+    prefill_ns: int = 0
     done_ns: int = 0
     rejected: bool = False
     _done_lock: threading.Lock = dataclasses.field(
@@ -264,6 +268,7 @@ class EngineCore:
                         return
                     slot = self._free.pop(0)
                 req = self._queue._q.popleft()
+                req.slot_ns = time.monotonic_ns()
                 with self._admitted_lock:
                     self._admitted[slot] = req
             # detached: prefills are admitted from inside a decode task but
@@ -286,7 +291,9 @@ class EngineCore:
                 return
 
     def _prefill_task(self, req: Request, slot: int):
-        first = self._prefill_exec(req, slot)
+        req.prefill_ns = time.monotonic_ns()
+        with self.rt.tracer.span("serve.prefill", req.id):
+            first = self._prefill_exec(req, slot)
         self.budget[slot] = req.max_new_tokens
         req.tokens.append(first)
         if req.on_token:
@@ -298,30 +305,38 @@ class EngineCore:
         self.stats["prefills"] += 1
 
     def _decode_iter(self):
+        # the spans take the id of the task span around this body: the
+        # decode task's
+        tracer = self.rt.tracer
         live = [i for i, r in enumerate(self.active) if r is not None]
         if live:
-            nxt = self._decode_exec(live)
-            for i in live:
-                req = self.active[i]
-                tok = int(nxt[i])
-                req.tokens.append(tok)
-                self.stats["tokens"] += 1
-                if req.on_token:
-                    req.on_token(tok)
-                self.pos[i] += 1
-                self.budget[i] -= 1
-                if self.budget[i] <= 0 or self.pos[i] >= self.max_seq - 1:
-                    self.active[i] = None
-                    with self._free_lock:
-                        self._free.append(i)
-                    self._retire(req)
+            with tracer.span("serve.decode"):
+                nxt = self._decode_exec(live)
+            with tracer.span("serve.emit"):
+                for i in live:
+                    req = self.active[i]
+                    tok = int(nxt[i])
+                    req.tokens.append(tok)
+                    self.stats["tokens"] += 1
+                    if req.on_token:
+                        req.on_token(tok)
+                    self.pos[i] += 1
+                    self.budget[i] -= 1
+                    if self.budget[i] <= 0 or \
+                            self.pos[i] >= self.max_seq - 1:
+                        self.active[i] = None
+                        with self._free_lock:
+                            self._free.append(i)
+                        self._retire(req)
             self.stats["decode_iters"] += 1
-        self._admit()
+        with tracer.span("serve.admit"):
+            self._admit()
         if not self._stop:
             # idle backoff is a wall-clock pause: skipped under the
             # schedule explorer, where it would stall the serialized world
             if not live and self.rt._explorer is None:
-                time.sleep(0.002)
+                with tracer.span("serve.idle"):
+                    time.sleep(0.002)
             # detached: the loop respawns itself — parenting iteration N+1
             # on N would chain completion tokens forever and pin every
             # decode Task in memory until stop()
@@ -539,13 +554,17 @@ class ServeEngine(EngineCore):
         import jax.numpy as jnp
 
         from repro.models import api as mapi
+        tracer = self.rt.tracer
         batch = {"tokens": jnp.asarray(tokens)[None, :]}
-        logits, _, cache = mapi.forward(self.cfg, self.params, batch, self.sh,
-                                        mode="prefill")
-        last = logits[0, -1]
-        if not bool(jnp.all(jnp.isfinite(last))):
-            raise FloatingPointError("prefill produced non-finite logits")
-        return int(jnp.argmax(last)), cache
+        with tracer.span("serve.prefill.forward"):
+            logits, _, cache = mapi.forward(self.cfg, self.params, batch,
+                                            self.sh, mode="prefill")
+        with tracer.span("serve.prefill.sync"):
+            last = logits[0, -1]
+            if not bool(jnp.all(jnp.isfinite(last))):
+                raise FloatingPointError("prefill produced non-finite logits")
+            first = int(jnp.argmax(last))
+        return first, cache
 
     # ---------------------------------------------------------- core hooks
     def _prefill_exec(self, req: Request, slot: int) -> int:
@@ -564,25 +583,31 @@ class ServeEngine(EngineCore):
                     dst, src.astype(dst.dtype),
                     (0, slot) + (0,) * (dst.ndim - 2))
             return dst
-        self.cache = jax.tree_util.tree_map(splice, self.cache, cache)
+        with self.rt.tracer.span("serve.prefill.splice"):
+            self.cache = jax.tree_util.tree_map(splice, self.cache, cache)
         self.pos[slot] = L
         return first
 
     def _decode_exec(self, live: list) -> np.ndarray:
         import jax.numpy as jnp
-        toks = np.zeros((self.n_slots, 1), np.int32)
-        for i in live:
-            toks[i, 0] = self.active[i].tokens[-1]
-        # per-slot cache positions (continuous batching): idle slots
-        # write harmlessly into their own stale position
-        nxt, finite, self.cache = self._decode_fn(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(self.pos))
-        finite = np.asarray(finite)
-        bad = [i for i in live if not finite[i]]
-        if bad:
-            raise FloatingPointError(
-                f"decode produced non-finite logits in slots {bad}")
-        return np.asarray(nxt)
+        tracer = self.rt.tracer
+        with tracer.span("serve.decode.inputs"):
+            toks = np.zeros((self.n_slots, 1), np.int32)
+            for i in live:
+                toks[i, 0] = self.active[i].tokens[-1]
+            # per-slot cache positions (continuous batching): idle slots
+            # write harmlessly into their own stale position
+            toks, pos = jnp.asarray(toks), jnp.asarray(self.pos)
+        with tracer.span("serve.decode.launch"):
+            nxt, finite, self.cache = self._decode_fn(
+                self.params, self.cache, toks, pos)
+        with tracer.span("serve.decode.sync"):
+            finite = np.asarray(finite)
+            bad = [i for i in live if not finite[i]]
+            if bad:
+                raise FloatingPointError(
+                    f"decode produced non-finite logits in slots {bad}")
+            return np.asarray(nxt)
 
 
 def _decode_batch(cfg, sh, params, cache, tokens, pos):
