@@ -2,9 +2,12 @@
 (single token vs KV cache), cross-attention, bidirectional encoder attention.
 
 Supports RoPE, qk-norm, qkv-bias, logit softcap (gemma2), sliding-window
-local layers alternating with global layers. Pure-jnp path is the default
-(used for dry-run lowering); the Pallas flash kernel (kernels/flash_attention)
-is selected with use_pallas=True for TPU runs.
+local layers alternating with global layers. Every path is pure jnp; the
+Pallas flash kernel (kernels/flash_attention) is not called from here.
+
+Decode writes each sequence's new K/V row into the stacked (L,B,T,KV,hd)
+cache in place and attends over the layer's whole T positions under a
+causal (and, on local layers, sliding-window) mask.
 """
 from __future__ import annotations
 
@@ -124,49 +127,71 @@ def full_attention(cfg, p, x, sh: Sharder, *, causal=True, is_local=None,
     return y, (k, v)
 
 
-def decode_attention(cfg, p, x, cache_k, cache_v, cache_pos, sh: Sharder,
-                     *, is_local=None):
-    """Single-token decode. x:(B,1,D); cache:(B,T,KV,hd); cache_pos is a
-    scalar (aligned batch) or an int32 (B,) vector (continuous batching:
-    per-sequence positions).
-
-    Returns (out, new_cache_k, new_cache_v).
-    """
-    B = x.shape[0]
-    T = cache_k.shape[1]
-    cache_pos = jnp.asarray(cache_pos, jnp.int32)
-    per_seq = cache_pos.ndim == 1
-    if per_seq:
-        positions = cache_pos[:, None]  # (B, 1)
-    else:
-        positions = jnp.full((B, 1), cache_pos, dtype=jnp.int32)
-    q, k_new, v_new = _project_qkv(cfg, p, x, positions)
-    if per_seq:
-        bidx = jnp.arange(B)
-        cache_k = cache_k.at[bidx, cache_pos].set(
-            k_new[:, 0].astype(cache_k.dtype))
-        cache_v = cache_v.at[bidx, cache_pos].set(
-            v_new[:, 0].astype(cache_v.dtype))
-    else:
-        cache_k = jax.lax.dynamic_update_slice(
-            cache_k, k_new.astype(cache_k.dtype), (0, cache_pos, 0, 0))
-        cache_v = jax.lax.dynamic_update_slice(
-            cache_v, v_new.astype(cache_v.dtype), (0, cache_pos, 0, 0))
+def _cache_names(cache, sh: Sharder):
+    """Logical axes of a stacked cache (L, B, T, KV, hd)."""
     model_size = 1
     if sh.mesh is not None and "model" in getattr(sh.mesh, "axis_names", ()):
         model_size = sh.mesh.shape["model"]
-    if cache_k.shape[2] % model_size == 0:
-        names = ("batch", "cache_seq", "kv_act", None)
-    else:  # KV heads can't cover the TP axis: shard cache sequence instead
-        names = ("batch", "cache_seq_model", None, None)
-    cache_k = sh.act(cache_k, *names)
-    cache_v = sh.act(cache_v, *names)
+    if cache.shape[3] % model_size == 0:
+        return (None, "batch", "cache_seq", "kv_act", None)
+    # KV heads can't cover the TP axis: shard cache sequence instead
+    return (None, "batch", "cache_seq_model", None, None)
+
+
+def _write_row(cache, new, layer, cache_pos):
+    """Write new:(B,1,KV,hd) into cache:(L,B,T,KV,hd) at [layer, b, pos[b]]
+    (per-sequence positions) or [layer, :, pos] (scalar), in place."""
+    new = new.astype(cache.dtype)
+    if cache_pos.ndim == 1:
+        return cache.at[layer, jnp.arange(new.shape[0]), cache_pos].set(
+            new[:, 0])
+    return jax.lax.dynamic_update_slice(cache, new[None],
+                                        (layer, 0, cache_pos, 0, 0))
+
+
+def decode_attention_stacked(cfg, p, x, cache_k, cache_v, layer, cache_pos,
+                             sh: Sharder, *, is_local=None):
+    """Single-token decode of layer ``layer`` against the stacked cache.
+
+    x:(B,1,D); cache:(L,B,T,KV,hd); cache_pos is a scalar (aligned batch) or
+    an int32 (B,) vector (continuous batching: per-sequence positions). The
+    new K/V row goes into the stacked cache in place, and attention reads
+    ``cache[layer]`` after the write, so a layer scan that carries the cache
+    keeps one copy of it.
+
+    Returns (out, new_cache_k, new_cache_v), the caches stacked.
+    """
+    B = x.shape[0]
+    T = cache_k.shape[2]
+    cache_pos = jnp.asarray(cache_pos, jnp.int32)
+    if cache_pos.ndim == 1:
+        positions = cache_pos[:, None]  # (B, 1)
+        qpos = positions
+    else:
+        positions = jnp.full((B, 1), cache_pos, dtype=jnp.int32)
+        qpos = jnp.full((1,), cache_pos, jnp.int32)
+    q, k_new, v_new = _project_qkv(cfg, p, x, positions)
+    names = _cache_names(cache_k, sh)
+    cache_k = sh.act(_write_row(cache_k, k_new, layer, cache_pos), *names)
+    cache_v = sh.act(_write_row(cache_v, v_new, layer, cache_pos), *names)
     kpos = jnp.arange(T, dtype=jnp.int32)
-    qpos = positions if per_seq else jnp.full((1,), cache_pos, jnp.int32)
     mask = _mask(qpos, kpos, True, cfg.sliding_window, is_local)
-    out = _sdpa(cfg, q, cache_k, cache_v, mask, sh)
+    out = _sdpa(cfg, q, cache_k[layer], cache_v[layer], mask, sh)
     y = jnp.einsum("bsh,hd->bsd", out.reshape(B, 1, -1), p["wo"].astype(x.dtype))
     return y, cache_k, cache_v
+
+
+def decode_attention(cfg, p, x, cache_k, cache_v, cache_pos, sh: Sharder,
+                     *, is_local=None):
+    """Single-token decode against one layer's cache (B,T,KV,hd): the
+    stacked path over a stack of one.
+
+    Returns (out, new_cache_k, new_cache_v).
+    """
+    y, ck, cv = decode_attention_stacked(cfg, p, x, cache_k[None],
+                                         cache_v[None], 0, cache_pos, sh,
+                                         is_local=is_local)
+    return y, ck[0], cv[0]
 
 
 def cross_attention(cfg, p, x, enc_k, enc_v, sh: Sharder):

@@ -129,12 +129,15 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 
 def sinusoidal_positions(seq_len: int, d_model: int, offset=0) -> jax.Array:
-    pos = (jnp.arange(seq_len) + offset)[:, None].astype(jnp.float32)
-    dim = jnp.arange(0, d_model, 2)[None, :].astype(jnp.float32)
+    """(seq_len, d_model) from a scalar offset; (B, seq_len, d_model) from a
+    (B,) vector of per-sequence offsets."""
+    offset = jnp.asarray(offset)[..., None]
+    pos = (jnp.arange(seq_len) + offset)[..., None].astype(jnp.float32)
+    dim = jnp.arange(0, d_model, 2).astype(jnp.float32)
     angle = pos / jnp.power(10_000.0, dim / d_model)
-    pe = jnp.zeros((seq_len, d_model), jnp.float32)
-    pe = pe.at[:, 0::2].set(jnp.sin(angle))
-    pe = pe.at[:, 1::2].set(jnp.cos(angle[:, : (d_model - d_model // 2)]))
+    pe = jnp.zeros(angle.shape[:-1] + (d_model,), jnp.float32)
+    pe = pe.at[..., 0::2].set(jnp.sin(angle))
+    pe = pe.at[..., 1::2].set(jnp.cos(angle[..., : (d_model - d_model // 2)]))
     return pe
 
 

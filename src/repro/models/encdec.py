@@ -73,7 +73,7 @@ def forward_encdec(cfg, params, tokens, sh: Sharder, *, frames=None,
     else:  # decode
         x = jnp.take(params["embed"]["table"], tokens, axis=0).astype(dt)
         pos = sinusoidal_positions(1, cfg.d_model, offset=cache_pos)
-        x = x + pos.astype(dt)[None]
+        x = x + pos.astype(dt)  # (1, D), or (B, 1, D) per-slot
         x = sh.act(x, "batch", "seq", None)
 
         def body(x, xs):

@@ -117,13 +117,14 @@ def _dense_forward(cfg, params, tokens, sh, mode, cache, cache_pos, q_chunk):
             k, v = kvs
             new_cache = {"k": k, "v": v}  # (L, B, S, KV, hd)
         aux = jnp.sum(auxs)
-    else:  # decode
-        def body(x, xs):
-            lp, ck, cv, is_local = xs
+    else:  # decode: the stacked cache is carried and updated in place
+        def body(carry, xs):
+            x, ck, cv = carry
+            lp, layer, is_local = xs
             il = is_local if flags is not None else None
             h = apply_norm(cfg, x, lp["ln1"])
-            out, nk, nv = attn.decode_attention(cfg, lp["attn"], h, ck, cv,
-                                                cache_pos, sh, is_local=il)
+            out, ck, cv = attn.decode_attention_stacked(
+                cfg, lp["attn"], h, ck, cv, layer, cache_pos, sh, is_local=il)
             if "post_attn_ln" in lp:
                 out = apply_norm(cfg, out, lp["post_attn_ln"])
             x = x + out
@@ -134,11 +135,11 @@ def _dense_forward(cfg, params, tokens, sh, mode, cache, cache_pos, q_chunk):
                 y = _mlp(cfg, lp["mlp"], h2, sh)
             if "post_mlp_ln" in lp:
                 y = apply_norm(cfg, y, lp["post_mlp_ln"])
-            return x + y, (nk, nv)
+            return (x + y, ck, cv), None
 
-        x, (nk, nv) = jax.lax.scan(body, x,
-                                   (params["layers"], cache["k"], cache["v"],
-                                    xs_flags))
+        layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+        (x, nk, nv), _ = jax.lax.scan(body, (x, cache["k"], cache["v"]),
+                                      (params["layers"], layers, xs_flags))
         new_cache = {"k": nk, "v": nv}
         aux = jnp.float32(0)
 

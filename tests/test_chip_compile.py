@@ -11,6 +11,7 @@ imported: only one process at a time may load the TPU library, and under
 several test workers only the worker given this file may do so.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -100,17 +101,34 @@ def _serving_params(cfg, one_chip):
     return _on(one_chip, abstract_params(cfg, jnp.dtype(cfg.dtype)))
 
 
-def test_qwen3_decode_step_fits_one_v5e(one_chip):
-    """The serve engine's own decode step, full width, 8 slots x 4096."""
+@pytest.fixture(scope="module")
+def qwen3_decode(one_chip):
+    """The serve engine's own decode step, full width, 8 slots x 4096, the
+    cache donated, compiled for one v5e."""
     cfg = get_config("qwen3-1.7b")
     cache = _on(one_chip, mapi.abstract_cache(cfg, 8, 4096))
     tokens = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
     pos = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
     step = jax.jit(functools.partial(_decode_batch, cfg, NULL_SHARDER),
                    donate_argnums=(1,))
-    compiled = step.lower(_serving_params(cfg, one_chip), cache, tokens,
-                          pos).compile()
-    _fits(compiled)
+    return step.lower(_serving_params(cfg, one_chip), cache, tokens,
+                      pos).compile()
+
+
+def test_qwen3_decode_step_fits_one_v5e(qwen3_decode):
+    _fits(qwen3_decode)
+
+
+def test_qwen3_decode_updates_cache_in_place(qwen3_decode):
+    """The layer scan carries the stacked cache: no second cache in temp
+    memory, and no copy or dynamic-update-slice of a whole K or V stack
+    (the scan's per-layer outputs written into a fresh stack and copied
+    back into the donated buffer)."""
+    assert qwen3_decode.memory_analysis().temp_size_in_bytes < 64 * 2**20
+    stack_ops = re.findall(r"= bf16\[28,8,4096,8,128\]\{[^}]*\} ([\w-]+)\(",
+                           qwen3_decode.as_text())
+    assert stack_ops, "no whole-stack op found: the pattern is stale"
+    assert not {"copy", "dynamic-update-slice"} & set(stack_ops), stack_ops
 
 
 def test_qwen3_prefill_fits_one_v5e(one_chip):

@@ -8,6 +8,7 @@ import pytest
 
 from repro.configs import ARCH_IDS, get_config
 from repro.launch.steps import TrainConfig, init_train_state, make_train_step
+from repro.models import api as mapi
 from repro.models import forward, init_params
 from repro.models.api import loss_fn, shift_labels
 from repro.models.common import NULL_SHARDER
@@ -53,33 +54,78 @@ def test_smoke_train_step(arch):
         assert bool(jnp.all(jnp.isfinite(leaf)))
 
 
+def _batch_axis(cfg, T):
+    """Per cache leaf, the axis that holds the batch."""
+    one, two = mapi.cache_shapes(cfg, 1, T), mapi.cache_shapes(cfg, 2, T)
+    return jax.tree_util.tree_map(
+        lambda a, b: next(i for i, (m, n) in enumerate(zip(a[0], b[0]))
+                          if m != n),
+        one, two, is_leaf=mapi._is_shape_leaf)
+
+
+def _kv_leaves(cache):
+    """The (L, B, T, KV, hd) self-attention K/V stacks of a cache tree."""
+    if "attn" in cache:
+        cache = cache["attn"]
+    return [cache[k] for k in ("k", "v") if k in cache]
+
+
+@pytest.mark.parametrize("positions", ["scalar", "per_slot"])
 @pytest.mark.parametrize("arch", ARCH_IDS)
-def test_prefill_decode_consistency(arch):
+def test_prefill_decode_consistency(arch, positions):
+    """Each sequence is prefilled alone at its own length, spliced into a
+    batched cache, and decoded one token at a scalar position (every
+    sequence the same length) or per-slot positions (distinct lengths).
+    The decode logits match a forward over the whole sequence; the decode
+    writes exactly the K/V rows [layer, b, pos[b]] and they hold the new
+    token's K/V."""
     cfg = get_config(arch, smoke=True)
     if cfg.family == "moe":
         cfg = dataclasses.replace(cfg, moe_capacity_factor=64.0)  # dropless
     params = init_params(cfg, KEY)
     B, S, T = 2, 16, 32
+    lens = [S, S] if positions == "scalar" else [S - 7, S]
     batch = _batch(cfg, B, S)
     nxt = jax.random.randint(jax.random.PRNGKey(2), (B, 1), 0, cfg.vocab_size)
-    full = dict(batch, tokens=jnp.concatenate([batch["tokens"], nxt], 1))
-    logits_full, _, _ = forward(cfg, params, full, mode="train")
-    _, _, cache = forward(cfg, params, batch, mode="prefill")
+    if cfg.family == "encdec":  # the encoder cache fills T // ratio frames
+        frames = jax.random.normal(
+            KEY, (B, T // cfg.encoder_frames_ratio, cfg.d_model))
 
-    def pad(x):
-        w = [(0, 0)] * x.ndim
-        w[2] = (0, T - S)
-        return jnp.pad(x, w)
+    def seq(b, n):
+        one = {"tokens": batch["tokens"][b:b + 1, :n]}
+        if cfg.family == "encdec":
+            one["frames"] = frames[b:b + 1]
+        return one
 
-    if cfg.family in ("dense", "moe", "encdec"):
-        cache = {k: (pad(v) if k in ("k", "v") else v)
-                 for k, v in cache.items()}
-    elif cfg.family == "hybrid":
-        cache["attn"] = {k: pad(v) for k, v in cache["attn"].items()}
-    d_logits, _, _ = forward(cfg, params, {"tokens": nxt}, mode="decode",
-                             cache=cache, cache_pos=S)
-    err = float(jnp.max(jnp.abs(logits_full[:, S, :] - d_logits[:, -1, :])))
-    assert err < 2e-2, err
+    cache = mapi.init_cache(cfg, B, T)
+    axes = _batch_axis(cfg, T)
+    refs = []
+    for b, n in enumerate(lens):
+        _, _, c = forward(cfg, params, seq(b, n), mode="prefill")
+        cache = jax.tree_util.tree_map(
+            lambda dst, src, ax: jax.lax.dynamic_update_slice_in_dim(
+                dst, src.astype(dst.dtype), b, ax), cache, c, axes)
+        full = seq(b, n)
+        full["tokens"] = jnp.concatenate([full["tokens"], nxt[b:b + 1]], 1)
+        refs.append(forward(cfg, params, full, mode="prefill"))
+
+    pos = S if positions == "scalar" else jnp.asarray(lens, jnp.int32)
+    d_logits, _, new = forward(cfg, params, {"tokens": nxt}, mode="decode",
+                               cache=cache, cache_pos=pos)
+    for b, n in enumerate(lens):
+        err = float(jnp.max(jnp.abs(refs[b][0][0, n] - d_logits[b, -1])))
+        assert err < 2e-2, (b, err)
+
+    for old, upd, *ref in zip(_kv_leaves(cache), _kv_leaves(new),
+                              *(_kv_leaves(r[2]) for r in refs)):
+        written = jnp.zeros(old.shape[:3], bool)
+        for b, n in enumerate(lens):
+            written = written.at[:, b, n].set(True)
+            row = upd[:, b, n].astype(jnp.float32)
+            want = ref[b][:, 0, n].astype(jnp.float32)
+            assert float(jnp.max(jnp.abs(row - want))) < 5e-2, b
+        kept = ~written[..., None, None]
+        assert bool(jnp.all(jnp.where(kept, upd == old, True)))
 
 
 def test_param_counts_close_to_published():
