@@ -25,7 +25,7 @@ import numpy as np
 from chipbench import devtrace, traffic
 from chipbench.engine import Client, SpanEngine, now_ns
 from chipbench.reference import Reference, served_gaps
-from chipbench.weights import make_weights
+from chipbench.weights import layout, make_weights
 from chipbench.work import Shapes
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parents[1]
@@ -142,27 +142,79 @@ class GcClock:
             self._t0 = None
 
 
+# configuration-file key -> the program's ModelConfig field
+FIELDS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
+          "num_attention_heads": "n_heads",
+          "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
+          "intermediate_size": "d_ff", "vocab_size": "vocab_size",
+          "tie_word_embeddings": "tie_embeddings", "qk_norm": "qk_norm",
+          "qkv_bias": "qkv_bias", "mlp_gated": "mlp_gated",
+          "norm": "norm_type", "norm_eps": "rms_eps",
+          "rope_theta": "rope_theta"}
+# the MLPs the reference computes: the program's (mlp_gated, mlp_act) for
+# each ``hidden_act``
+ACTIVATIONS = {(True, "silu"): "silu", (False, "gelu"): "gelu_pytorch_tanh"}
+
+
+def _program_tree(pc) -> dict:
+    """{path: shape} of every leaf of the program's parameter tree."""
+    from repro.models.params import ParamSpec, build_param_specs
+
+    def flat(node, prefix):
+        for k, v in node.items():
+            if isinstance(v, ParamSpec):
+                yield prefix + (k,), tuple(v.shape)
+            else:
+                yield from flat(v, prefix + (k,))
+    return dict(flat(build_param_specs(pc), ()))
+
+
+def _program_window(pc):
+    """The attention window the program applies (0: none), as
+    ``models/lm.py`` ``is_local_flags`` and ``attention._mask`` apply it."""
+    w, period = pc.sliding_window, pc.local_global_period
+    if not w or period in (0, 1):
+        return w  # on every layer
+    return f"{w} on {period - 1} of every {period} layers"
+
+
+def program_architecture(pc) -> dict:
+    """The architecture the program computes, under the configuration
+    file's keys: the inverse of ``program_config``. ``out_bias`` is read
+    from the parameter tree, which has no flag for it."""
+    arch = {k: getattr(pc, f) for k, f in FIELDS.items()}
+    arch.update(hidden_act=ACTIVATIONS.get((pc.mlp_gated, pc.mlp_act),
+                                           f"{pc.mlp_act}, not computed by "
+                                           "the reference"),
+                out_bias=("layers", "attn", "bo") in _program_tree(pc),
+                attention_window=_program_window(pc))
+    return arch
+
+
 def program_config(cfg: dict):
-    """The program's configuration for this file, checked against it."""
+    """The program's configuration for this file, checked against the
+    file's whole architecture before any weights are drawn: every key of
+    ``program_architecture`` and the served dtype, and the program's
+    parameter tree against the one ``weights.layout`` draws, path by path
+    and shape by shape. A program that would ignore a leaf the file draws
+    (a bias, a norm's shift) is refused here, where the logit-gap check
+    could pass it within its limit."""
     from repro.configs import get_config
     s = cfg["serve"]
     pc = get_config(s["repro_arch"], smoke=s.get("repro_smoke", False))
-    want = {"n_layers": cfg["num_hidden_layers"], "d_model": cfg["hidden_size"],
-            "n_heads": cfg["num_attention_heads"],
-            "n_kv_heads": cfg["num_key_value_heads"],
-            "head_dim": cfg["head_dim"], "d_ff": cfg["intermediate_size"],
-            "vocab_size": cfg["vocab_size"],
-            "tie_embeddings": cfg["tie_word_embeddings"],
-            "qk_norm": cfg["qk_norm"], "qkv_bias": cfg["qkv_bias"],
-            "mlp_gated": cfg["mlp_gated"], "norm_type": cfg["norm"],
-            "rms_eps": cfg["norm_eps"],
-            "rope_theta": float(cfg["rope_theta"]),
-            "dtype": s["dtype"]}
-    got = {k: getattr(pc, k) for k in want}
-    if got != want:
-        bad = {k: (got[k], v) for k, v in want.items() if got[k] != v}
+    bad = [f"{k}: file {cfg[k]!r}, program {v!r}"
+           for k, v in program_architecture(pc).items() if cfg[k] != v]
+    if s["dtype"] != pc.dtype:
+        bad.append(f"dtype: file {s['dtype']!r}, program {pc.dtype!r}")
+    want = {p: shape for p, (shape, _) in layout(cfg, pc.vocab_padded).items()}
+    have = _program_tree(pc)
+    for p in sorted(want.keys() | have.keys()):
+        if want.get(p) != have.get(p):
+            bad.append(f"{'.'.join(p)}: file draws {want.get(p, 'none')}, "
+                       f"program has {have.get(p, 'none')}")
+    if bad:
         raise ValueError(f"program config {pc.name} departs from the "
-                         f"configuration file (program, file): {bad}")
+                         "configuration file:\n  " + "\n  ".join(bad))
     return pc
 
 

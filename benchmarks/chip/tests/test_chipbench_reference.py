@@ -1,7 +1,8 @@
 """The float32 reference: against the program's own forward pass on both
-smoke presets, and against Hugging Face's implementations of Qwen3 and
-StarCoder2 (a witness that imports nothing of the program or of the
-benchmark)."""
+smoke presets, with the flags the program computes (its departures from the
+published models are ``program_config``'s to catch), and against Hugging
+Face's implementations of Qwen3 and StarCoder2 as published (a witness that
+imports nothing of the program or of the benchmark)."""
 import dataclasses
 import os
 
@@ -9,21 +10,25 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from chipbench.harness import program_architecture
 from chipbench.reference import Reference, control_gaps, served_gaps
 from chipbench.weights import make_weights
 
+# both architectures as published (Qwen/Qwen3-1.7B, bigcode/starcoder2-3b
+# config.json) at the smoke presets' widths; StarCoder2's use_bias puts a
+# bias on q, k, v, o and both MLP matrices
 QWEN3_SMOKE = {
     "hidden_size": 64, "intermediate_size": 160, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
-    "vocab_size": 512, "rope_theta": 10000.0, "tie_word_embeddings": True,
+    "vocab_size": 512, "rope_theta": 1000000.0, "tie_word_embeddings": True,
     "norm": "rmsnorm", "norm_eps": 1e-6, "qk_norm": True, "mlp_gated": True,
     "qkv_bias": False, "out_bias": False, "attention_window": 0}
 STARCODER2_SMOKE = {
     "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
-    "vocab_size": 512, "rope_theta": 999999.0, "tie_word_embeddings": True,
-    "norm": "layernorm", "norm_eps": 1e-6, "qk_norm": False,
-    "mlp_gated": False, "qkv_bias": True, "out_bias": False,
+    "vocab_size": 512, "rope_theta": 999999.4420358813,
+    "tie_word_embeddings": True, "norm": "layernorm", "norm_eps": 1e-5,
+    "qk_norm": False, "mlp_gated": False, "qkv_bias": True, "out_bias": True,
     "attention_window": 4096}
 
 
@@ -31,23 +36,24 @@ def _tokens(seed, n, vocab):
     return np.random.default_rng(seed).integers(0, vocab, n, dtype=np.int32)
 
 
-def _program_logits(name, weights, tokens):
-    from repro.configs import get_config
+def _program_logits(pc, weights, tokens):
     from repro.models import api
-    cfg = dataclasses.replace(get_config(name, smoke=True), dtype="float32")
+    cfg = dataclasses.replace(pc, dtype="float32")
     logits, _, _ = api.forward(cfg, weights, {"tokens": jnp.asarray(tokens)[None]},
                                mode="train")
     return np.asarray(logits[0, :, :cfg.vocab_size])
 
 
-@pytest.mark.parametrize("name,cfg", [("qwen3_1_7b", QWEN3_SMOKE),
-                                      ("starcoder2_3b", STARCODER2_SMOKE)])
+@pytest.mark.parametrize("name", ["qwen3_1_7b", "starcoder2_3b"])
 @pytest.mark.parametrize("seed", [1, 2**31 + 5])
-def test_reference_matches_the_program_on_smoke_presets(name, cfg, seed):
-    w = make_weights(cfg, seed, cfg["vocab_size"], dtype=jnp.float32)
+def test_reference_matches_the_program_on_smoke_presets(name, seed):
+    from repro.configs import get_config
+    pc = get_config(name, smoke=True)
+    cfg = program_architecture(pc)
+    w = make_weights(cfg, seed, pc.vocab_padded, dtype=jnp.float32)
     t = _tokens(seed, 48, cfg["vocab_size"])
     ref = np.asarray(Reference(cfg).logits(w, t, np.arange(48)))
-    prog = _program_logits(name, w, t)
+    prog = _program_logits(pc, w, t)
     assert np.abs(ref - prog).max() < 1e-4 * max(1.0, np.abs(ref).max())
     # the served-token gap of the program's own greedy tokens is rounding
     assert served_gaps(ref, prog.argmax(-1)).max() < 1e-4
@@ -123,10 +129,7 @@ def _hf_logits(cfg, w, tokens, starcoder):
 
 @pytest.mark.parametrize("starcoder", [False, True])
 def test_reference_matches_transformers(starcoder):
-    # StarCoder2 here as published: biases on o and on both MLP matrices
-    # too, and LayerNorm eps 1e-5
-    cfg = dict(STARCODER2_SMOKE, out_bias=True, norm_eps=1e-5) if starcoder \
-        else QWEN3_SMOKE
+    cfg = STARCODER2_SMOKE if starcoder else QWEN3_SMOKE
     w = make_weights(cfg, 11, cfg["vocab_size"], dtype=jnp.float32)
     t = _tokens(11, 40, cfg["vocab_size"])
     ref = np.asarray(Reference(cfg).logits(w, t, np.arange(40)))

@@ -2,6 +2,7 @@
 by hand at both models' published widths."""
 import json
 import os
+import pathlib
 
 import pytest
 
@@ -49,17 +50,18 @@ def test_weight_bytes_starcoder2():
     assert s.weight_bytes() == 6_060_742_656
 
 
-def test_counts_match_the_programs_own_parameter_count():
-    from repro.configs import get_config
+@pytest.mark.parametrize("path", sorted(pathlib.Path(CONFIGS).glob("*.json")),
+                         ids=lambda p: p.stem)
+def test_counts_match_the_programs_own_parameter_count(path):
+    from chipbench.harness import program_config
     from repro.models.params import param_count_exact
-    q = get_config("qwen3_1_7b")
-    # the program pads the vocabulary to a multiple of 256
-    assert qwen3().param_count() == param_count_exact(q) - \
-        (q.vocab_padded - q.vocab_size) * q.d_model
-    sc = get_config("starcoder2_3b")
-    out_biases = 30 * (3072 + 12288 + 3072)  # published, not in the program
-    assert Shapes.from_config(STARCODER2_3B).param_count() == \
-        param_count_exact(sc) + out_biases
+    cfg = json.loads(path.read_text())
+    pc = program_config(cfg)
+    # the program pads the vocabulary to a multiple of 256, in the
+    # embedding and in an untied head
+    heads = 1 if pc.tie_embeddings else 2
+    assert Shapes.from_config(cfg).param_count() == param_count_exact(pc) - \
+        heads * (pc.vocab_padded - pc.vocab_size) * pc.d_model
 
 
 def test_decode_counts_by_hand():
