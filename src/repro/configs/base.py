@@ -29,6 +29,9 @@ class ModelConfig:
     n_kv_heads: int = 0
     head_dim: int = 0
     qkv_bias: bool = False
+    # biases on the attention output and both MLP matrices (StarCoder2's
+    # use_bias), beside the q, k, v biases of qkv_bias
+    out_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 10_000.0
     # gemma2-style alternating local/global attention. 0 => all-global.

@@ -1,9 +1,10 @@
 """GQA attention: train/prefill (full-seq, optionally q-chunked), decode
 (single token vs KV cache), cross-attention, bidirectional encoder attention.
 
-Supports RoPE, qk-norm, qkv-bias, logit softcap (gemma2), sliding-window
-local layers alternating with global layers. Every path is pure jnp; the
-Pallas flash kernel (kernels/flash_attention) is not called from here.
+Supports RoPE, qk-norm, qkv and output biases, logit softcap (gemma2),
+sliding-window local layers alternating with global layers. Every path is
+pure jnp; the Pallas flash kernel (kernels/flash_attention) is not called
+from here.
 
 Decode writes each sequence's new K/V row into the stacked (L,B,T,KV,hd)
 cache in place and attends over the layer's whole T positions under a
@@ -41,6 +42,15 @@ def _project_qkv(cfg, p, x, positions):
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
+
+
+def _out_proj(p, out, dtype):
+    """The output projection of attention heads out:(B,S,H*hd), and its
+    bias where the model has one, with the weights in the residual's dtype."""
+    y = jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(dtype))
+    if "bo" in p:
+        y = y + p["bo"].astype(dtype)
+    return y
 
 
 def _mask(qpos, kpos, causal: bool, window: int, is_local) -> jax.Array:
@@ -122,7 +132,7 @@ def full_attention(cfg, p, x, sh: Sharder, *, causal=True, is_local=None,
         _, outs = jax.lax.scan(body, None,
                                (jnp.arange(nq, dtype=jnp.int32), qs))
         out = outs.swapaxes(0, 1).reshape(B, S, q.shape[2], q.shape[3])
-    y = jnp.einsum("bsh,hd->bsd", out.reshape(B, S, -1), p["wo"].astype(x.dtype))
+    y = _out_proj(p, out.reshape(B, S, -1), x.dtype)
     y = sh.act(y, "batch", "seq", None)
     return y, (k, v)
 
@@ -177,8 +187,7 @@ def decode_attention_stacked(cfg, p, x, cache_k, cache_v, layer, cache_pos,
     kpos = jnp.arange(T, dtype=jnp.int32)
     mask = _mask(qpos, kpos, True, cfg.sliding_window, is_local)
     out = _sdpa(cfg, q, cache_k[layer], cache_v[layer], mask, sh)
-    y = jnp.einsum("bsh,hd->bsd", out.reshape(B, 1, -1), p["wo"].astype(x.dtype))
-    return y, cache_k, cache_v
+    return _out_proj(p, out.reshape(B, 1, -1), x.dtype), cache_k, cache_v
 
 
 def decode_attention(cfg, p, x, cache_k, cache_v, cache_pos, sh: Sharder,
@@ -206,8 +215,7 @@ def cross_attention(cfg, p, x, enc_k, enc_v, sh: Sharder):
     Se = enc_k.shape[1]
     mask = jnp.ones((S, Se), bool)
     out = _sdpa(cfg_norope, q, enc_k, enc_v, mask, sh)
-    y = jnp.einsum("bsh,hd->bsd", out.reshape(B, S, -1), p["wo"].astype(x.dtype))
-    return y
+    return _out_proj(p, out.reshape(B, S, -1), x.dtype)
 
 
 def encode_kv(cfg, p, enc_out):

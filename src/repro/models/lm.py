@@ -58,6 +58,8 @@ def lm_logits(cfg, params, x, sh: Sharder):
 
 def _mlp(cfg, p, x, sh: Sharder, d_ff_override=None):
     h = jnp.einsum("bsd,df->bsf", x, p["wi"].astype(x.dtype))
+    if "bi" in p:
+        h = h + p["bi"].astype(x.dtype)
     if cfg.mlp_gated:
         g = jnp.einsum("bsd,df->bsf", x, p["wg"].astype(x.dtype))
         h = activation(cfg.mlp_act, g) * h
@@ -65,6 +67,8 @@ def _mlp(cfg, p, x, sh: Sharder, d_ff_override=None):
         h = activation(cfg.mlp_act, h)
     h = sh.act(h, "batch", "seq", "heads_act")
     y = jnp.einsum("bsf,fd->bsd", h, p["wo"].astype(x.dtype))
+    if "bo" in p:
+        y = y + p["bo"].astype(x.dtype)
     return sh.act(y, "batch", "seq", None)
 
 

@@ -58,6 +58,8 @@ def _attn_specs(cfg: ModelConfig, L: int, cross: bool = False) -> dict:
     if cfg.qk_norm:
         d["q_norm"] = ParamSpec(pre + (hd,), pl + (None,), "ones")
         d["k_norm"] = ParamSpec(pre + (hd,), pl + (None,), "ones")
+    if cfg.out_bias:
+        d["bo"] = ParamSpec(pre + (D,), pl + ("embed",), "zeros")
     return d
 
 
@@ -71,6 +73,9 @@ def _mlp_specs(cfg: ModelConfig, L: int, d_ff: Optional[int] = None) -> dict:
     }
     if cfg.mlp_gated:
         d["wg"] = ParamSpec(pre + (D, F), pl + ("embed", "mlp"), fan_in=D)
+    if cfg.out_bias:
+        d["bi"] = ParamSpec(pre + (F,), pl + ("mlp",), "zeros")
+        d["bo"] = ParamSpec(pre + (D,), pl + ("embed",), "zeros")
     return d
 
 

@@ -101,18 +101,44 @@ def _serving_params(cfg, one_chip):
     return _on(one_chip, abstract_params(cfg, jnp.dtype(cfg.dtype)))
 
 
-@pytest.fixture(scope="module")
-def qwen3_decode(one_chip):
-    """The serve engine's own decode step, full width, 8 slots x 4096, the
-    cache donated, compiled for one v5e."""
-    cfg = get_config("qwen3-1.7b")
-    cache = _on(one_chip, mapi.abstract_cache(cfg, 8, 4096))
-    tokens = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
-    pos = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+def _decode_step(name, n_slots, max_seq, one_chip):
+    """The serve engine's own decode step, full width, the cache donated,
+    compiled for one v5e."""
+    cfg = get_config(name)
+    cache = _on(one_chip, mapi.abstract_cache(cfg, n_slots, max_seq))
+    tokens = jax.ShapeDtypeStruct((n_slots, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((n_slots,), jnp.int32, sharding=one_chip)
     step = jax.jit(functools.partial(_decode_batch, cfg, NULL_SHARDER),
                    donate_argnums=(1,))
     return step.lower(_serving_params(cfg, one_chip), cache, tokens,
                       pos).compile()
+
+
+def _assert_cache_in_place(compiled, stack):
+    """The layer scan carries the stacked cache: no second cache in temp
+    memory, and no copy or dynamic-update-slice of a whole K or V stack
+    (the scan's per-layer outputs written into a fresh stack and copied
+    back into the donated buffer)."""
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+    stack_ops = re.findall(re.escape(f"= bf16[{stack}]") +
+                           r"\{[^}]*\} ([\w-]+)\(", compiled.as_text())
+    assert stack_ops, "no whole-stack op found: the pattern is stale"
+    assert not {"copy", "dynamic-update-slice"} & set(stack_ops), stack_ops
+
+
+def _prefill_step(name, n_tokens, one_chip):
+    """One full-width prefill of an ``n_tokens`` prompt."""
+    cfg = get_config(name)
+    batch = {"tokens": jax.ShapeDtypeStruct((1, n_tokens), jnp.int32,
+                                            sharding=one_chip)}
+    step = jax.jit(make_prefill_step(cfg, NULL_SHARDER, TrainConfig()))
+    return step.lower(_serving_params(cfg, one_chip), batch).compile()
+
+
+@pytest.fixture(scope="module")
+def qwen3_decode(one_chip):
+    """Qwen3-1.7B's decode at 8 slots x 4096."""
+    return _decode_step("qwen3-1.7b", 8, 4096, one_chip)
 
 
 def test_qwen3_decode_step_fits_one_v5e(qwen3_decode):
@@ -120,22 +146,38 @@ def test_qwen3_decode_step_fits_one_v5e(qwen3_decode):
 
 
 def test_qwen3_decode_updates_cache_in_place(qwen3_decode):
-    """The layer scan carries the stacked cache: no second cache in temp
-    memory, and no copy or dynamic-update-slice of a whole K or V stack
-    (the scan's per-layer outputs written into a fresh stack and copied
-    back into the donated buffer)."""
-    assert qwen3_decode.memory_analysis().temp_size_in_bytes < 64 * 2**20
-    stack_ops = re.findall(r"= bf16\[28,8,4096,8,128\]\{[^}]*\} ([\w-]+)\(",
-                           qwen3_decode.as_text())
-    assert stack_ops, "no whole-stack op found: the pattern is stale"
-    assert not {"copy", "dynamic-update-slice"} & set(stack_ops), stack_ops
+    _assert_cache_in_place(qwen3_decode, "28,8,4096,8,128")
 
 
 def test_qwen3_prefill_fits_one_v5e(one_chip):
-    """One full-width prefill of a 2048-token prompt."""
-    cfg = get_config("qwen3-1.7b")
-    batch = {"tokens": jax.ShapeDtypeStruct((1, 2048), jnp.int32,
-                                            sharding=one_chip)}
-    step = jax.jit(make_prefill_step(cfg, NULL_SHARDER, TrainConfig()))
-    compiled = step.lower(_serving_params(cfg, one_chip), batch).compile()
-    _fits(compiled)
+    _fits(_prefill_step("qwen3-1.7b", 2048, one_chip))
+
+
+@pytest.fixture(scope="module")
+def starcoder2_decode(one_chip):
+    """StarCoder2-3B's decode at 16 slots x 4096, as the benchmark's
+    configuration file serves it: 6.06 GB of weights beside a 2.01 GB
+    cache."""
+    return _decode_step("starcoder2-3b", 16, 4096, one_chip)
+
+
+def test_starcoder2_decode_step_fits_one_v5e(starcoder2_decode):
+    _fits(starcoder2_decode)
+
+
+def test_starcoder2_decode_updates_cache_in_place(starcoder2_decode):
+    _assert_cache_in_place(starcoder2_decode, "30,16,4096,2,128")
+
+
+def test_starcoder2_cache_has_no_tile_padding(starcoder2_decode):
+    """The donated cache is exactly its elements: 30 layers x 16 slots x
+    4096 positions x 2 KV heads x 128 x 2 bytes, for K and for V. A tiling
+    that padded the 2-head dimension to 8 or 16 rows would hold it four or
+    eight times over."""
+    assert starcoder2_decode.memory_analysis().alias_size_in_bytes == \
+        2 * 30 * 16 * 4096 * 2 * 128 * 2 == 2_013_265_920
+
+
+def test_starcoder2_prefill_fits_one_v5e(one_chip):
+    """The code-completion mix's longest prompt, 3584 tokens."""
+    _fits(_prefill_step("starcoder2-3b", 3584, one_chip))
