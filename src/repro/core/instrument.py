@@ -84,18 +84,20 @@ EVENTS = {
     "serve.idle.begin": 52,     # the decode loop's backoff with no slot live
     "serve.idle.end": 53,
     # ServeEngine child spans (same ids as their parent)
-    "serve.prefill.forward.begin": 54,  # the eager forward
-    "serve.prefill.forward.end": 55,
-    "serve.prefill.sync.begin": 56,     # finite check and argmax readback
-    "serve.prefill.sync.end": 57,
-    "serve.prefill.splice.begin": 58,   # the slot's cache splice
-    "serve.prefill.splice.end": 59,
+    "serve.prefill.forward.begin": 54,  # the bucket's prefill program's
+    "serve.prefill.forward.end": 55,    # launch (forward, head, splice)
+    "serve.prefill.sync.begin": 56,     # first token and finite flag read
+    "serve.prefill.sync.end": 57,       # back: waits out the program
+    "serve.prefill.splice.begin": 58,   # no longer recorded: the splice
+    "serve.prefill.splice.end": 59,     # runs inside the prefill program
     "serve.decode.inputs.begin": 60,    # token and position arrays
     "serve.decode.inputs.end": 61,
     "serve.decode.launch.begin": 62,    # the jitted step's call
     "serve.decode.launch.end": 63,
     "serve.decode.sync.begin": 64,      # finite flags and tokens read back
     "serve.decode.sync.end": 65,
+    "serve.prefill.pad": 66,    # one per prefill (arg: pad tokens between
+                                # the prompt and its length bucket)
 }
 
 # A span named X records the catalog events "X.begin" and "X.end"; the task

@@ -14,18 +14,22 @@ from repro.models.lm import forward_lm
 
 def forward(cfg: ModelConfig, params, batch: dict, sh: Sharder = NULL_SHARDER,
             *, mode="train", cache=None, cache_pos=None,
-            q_chunk: Optional[int] = None):
+            q_chunk: Optional[int] = None, logits_at=None):
     """batch: {"tokens": (B,S) int32 [, "frames": (B,Se,D) f32]}.
 
-    Returns (logits_f32, aux_loss, new_cache)."""
+    Returns (logits_f32, aux_loss, new_cache). The logits are of every
+    position, (B,S,V); with ``logits_at``, a position index that may be
+    traced, of that position alone, (B,1,V): the final norm and the LM
+    head then run on one row."""
     params = cast_params(params, dtype_of(cfg))
     if cfg.family == "encdec":
         return forward_encdec(cfg, params, batch["tokens"], sh,
                               frames=batch.get("frames"), mode=mode,
                               cache=cache, cache_pos=cache_pos,
-                              q_chunk=q_chunk)
+                              q_chunk=q_chunk, logits_at=logits_at)
     return forward_lm(cfg, params, batch["tokens"], sh, mode=mode,
-                      cache=cache, cache_pos=cache_pos, q_chunk=q_chunk)
+                      cache=cache, cache_pos=cache_pos, q_chunk=q_chunk,
+                      logits_at=logits_at)
 
 
 def loss_fn(cfg: ModelConfig, logits: jax.Array, labels: jax.Array,

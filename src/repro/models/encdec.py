@@ -11,7 +11,7 @@ import jax.numpy as jnp
 from repro.models import attention as attn
 from repro.models.common import (Sharder, apply_norm, dtype_of,
                                  sinusoidal_positions)
-from repro.models.lm import _maybe_remat, _mlp, lm_logits
+from repro.models.lm import _maybe_remat, _mlp, final_logits
 
 
 def encode(cfg, params, frames, sh: Sharder):
@@ -34,12 +34,13 @@ def encode(cfg, params, frames, sh: Sharder):
 
 def forward_encdec(cfg, params, tokens, sh: Sharder, *, frames=None,
                    enc_out=None, mode="train", cache=None, cache_pos=None,
-                   q_chunk: Optional[int] = None):
+                   q_chunk: Optional[int] = None, logits_at=None):
     """Teacher-forced decoder over encoder output.
 
     train/prefill: ``frames`` required; decode: ``cache`` holds self K/V and
     precomputed cross K/V (encoder ran at prefill).
-    Returns (logits, aux, new_cache).
+    Returns (logits, aux, new_cache); the logits of every position, or of
+    position ``logits_at`` alone.
     """
     dt = dtype_of(cfg)
     B, S = tokens.shape
@@ -92,5 +93,5 @@ def forward_encdec(cfg, params, tokens, sh: Sharder, *, frames=None,
                                     cache["xk"], cache["xv"]))
         new_cache = {"k": nk, "v": nv, "xk": cache["xk"], "xv": cache["xv"]}
 
-    x = apply_norm(cfg, x, params["final_norm"])
-    return lm_logits(cfg, params, x, sh), jnp.float32(0), new_cache
+    logits = final_logits(cfg, params, x, sh, logits_at)
+    return logits, jnp.float32(0), new_cache

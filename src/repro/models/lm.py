@@ -56,6 +56,15 @@ def lm_logits(cfg, params, x, sh: Sharder):
     return sh.act(logits, "batch", "seq", "vocab_act")
 
 
+def final_logits(cfg, params, x, sh: Sharder, at=None):
+    """The final norm and LM head over x:(B,S,D). With ``at``, a position
+    index (it may be traced), over that position alone -> (B,1,V)."""
+    if at is not None:
+        x = jax.lax.dynamic_slice_in_dim(x, at, 1, axis=1)
+    x = apply_norm(cfg, x, params["final_norm"])
+    return lm_logits(cfg, params, x, sh)
+
+
 def _mlp(cfg, p, x, sh: Sharder, d_ff_override=None):
     h = jnp.einsum("bsd,df->bsf", x, p["wi"].astype(x.dtype))
     if "bi" in p:
@@ -100,7 +109,8 @@ def _attn_block_full(cfg, lp, x, sh, is_local, q_chunk):
     return x + y, kv, aux
 
 
-def _dense_forward(cfg, params, tokens, sh, mode, cache, cache_pos, q_chunk):
+def _dense_forward(cfg, params, tokens, sh, mode, cache, cache_pos, q_chunk,
+                   logits_at):
     x = embed_tokens(cfg, params, tokens, sh)
     flags = is_local_flags(cfg)
     xs_flags = flags if flags is not None else jnp.zeros((cfg.n_layers,), bool)
@@ -147,12 +157,11 @@ def _dense_forward(cfg, params, tokens, sh, mode, cache, cache_pos, q_chunk):
         new_cache = {"k": nk, "v": nv}
         aux = jnp.float32(0)
 
-    x = apply_norm(cfg, x, params["final_norm"])
-    return lm_logits(cfg, params, x, sh), aux, new_cache
+    return final_logits(cfg, params, x, sh, logits_at), aux, new_cache
 
 
 # ---------------------------------------------------------------- ssm
-def _ssm_forward(cfg, params, tokens, sh, mode, cache, cache_pos):
+def _ssm_forward(cfg, params, tokens, sh, mode, cache, cache_pos, logits_at):
     x = embed_tokens(cfg, params, tokens, sh)
     keep = mode != "train"
 
@@ -173,8 +182,8 @@ def _ssm_forward(cfg, params, tokens, sh, mode, cache, cache_pos):
 
         x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
 
-    x = apply_norm(cfg, x, params["final_norm"])
-    return lm_logits(cfg, params, x, sh), jnp.float32(0), new_cache
+    logits = final_logits(cfg, params, x, sh, logits_at)
+    return logits, jnp.float32(0), new_cache
 
 
 # ---------------------------------------------------------------- hybrid
@@ -188,7 +197,8 @@ def _shared_attn_block_full(cfg, sp, x, sh, q_chunk, keep_cache):
     return x, (kv if keep_cache else None)
 
 
-def _hybrid_forward(cfg, params, tokens, sh, mode, cache, cache_pos, q_chunk):
+def _hybrid_forward(cfg, params, tokens, sh, mode, cache, cache_pos, q_chunk,
+                    logits_at):
     x = embed_tokens(cfg, params, tokens, sh)
     sp = params["shared_attn"]
     ssm_cfg = dataclasses.replace(cfg, family="ssm")
@@ -263,20 +273,23 @@ def _hybrid_forward(cfg, params, tokens, sh, mode, cache, cache_pos, q_chunk):
         new_cache = {"groups_ssm": ng_sts, "tail_ssm": n_tail,
                      "attn": {"k": nk, "v": nv}}
 
-    x = apply_norm(cfg, x, params["final_norm"])
-    return lm_logits(cfg, params, x, sh), jnp.float32(0), new_cache
+    logits = final_logits(cfg, params, x, sh, logits_at)
+    return logits, jnp.float32(0), new_cache
 
 
 # ---------------------------------------------------------------- dispatch
 def forward_lm(cfg, params, tokens, sh: Sharder, *, mode="train",
-               cache=None, cache_pos=None, q_chunk: Optional[int] = None):
-    """tokens: (B, S) int32. Returns (logits_f32, aux_loss, new_cache)."""
+               cache=None, cache_pos=None, q_chunk: Optional[int] = None,
+               logits_at=None):
+    """tokens: (B, S) int32. Returns (logits_f32, aux_loss, new_cache);
+    the logits of every position, or of position ``logits_at`` alone."""
     if cfg.family in ("dense", "moe"):
         return _dense_forward(cfg, params, tokens, sh, mode, cache,
-                              cache_pos, q_chunk)
+                              cache_pos, q_chunk, logits_at)
     if cfg.family == "ssm":
-        return _ssm_forward(cfg, params, tokens, sh, mode, cache, cache_pos)
+        return _ssm_forward(cfg, params, tokens, sh, mode, cache, cache_pos,
+                            logits_at)
     if cfg.family == "hybrid":
         return _hybrid_forward(cfg, params, tokens, sh, mode, cache,
-                               cache_pos, q_chunk)
+                               cache_pos, q_chunk, logits_at)
     raise ValueError(cfg.family)

@@ -18,13 +18,13 @@ batched decode without a global scheduler lock.
 Scale-out split (see docs/SERVING.md): :class:`EngineCore` is the
 model-agnostic half — admission queue, slot lifecycle, decode chain,
 per-hash-slot session state and the seal/drain hooks migration needs.
-:class:`ServeEngine` adds the jax model (prefill forward, batched decode,
-KV-cache splice) and is what a single-runtime deployment instantiates, with
-the exact pre-split behaviour. ``repro.serve.shard`` subclasses the core
-with a simulated backend whose decode *sleeps* (models device compute that
-releases the GIL, like a dispatched XLA kernel) so shard scaling is
-measurable in-process; ``repro.serve.router`` composes N cores into one
-sharded engine.
+:class:`ServeEngine` adds the jax model (a compiled prefill per prompt-length
+bucket that splices into the KV cache, and the batched decode) and is what
+a single-runtime deployment instantiates. ``repro.serve.shard`` subclasses
+the core with a simulated backend whose decode *sleeps* (models device
+compute that releases the GIL, like a dispatched XLA kernel) so shard
+scaling is measurable in-process; ``repro.serve.router`` composes N cores
+into one sharded engine.
 
 When the engine runs with ``shard_id`` set, its dependency addresses are
 namespaced per shard — N engines sharing one process (RuntimeCluster) must
@@ -273,10 +273,11 @@ class EngineCore:
                     self._admitted[slot] = req
             # detached: prefills are admitted from inside a decode task but
             # are not nested work of that iteration. The commutative "cache"
-            # access makes concurrent prefills mutually exclusive (the
-            # whole-tree cache splice is a read-modify-write) while leaving
-            # their order free — per-slot addresses alone would let two
-            # prefills interleave and lose one slot's KV.
+            # access makes concurrent prefills mutually exclusive (each
+            # prefill program takes the whole cache tree donated and returns
+            # it: a read-modify-write) while leaving their order free —
+            # per-slot addresses alone would let two prefills interleave and
+            # lose one slot's KV.
             t = self.group.spawn(self._prefill_task, (req, slot),
                                  name=f"prefill:{req.id}", detached=True,
                                  rw=[self._slot_addr(slot)],
@@ -519,17 +520,26 @@ class EngineCore:
 
 
 class ServeEngine(EngineCore):
-    """The jax-model engine: EngineCore + prefill forward, batched decode
-    and the KV-cache splice. Single-runtime deployments use this directly;
-    the sharded router drives one model engine (or simulated core) per
-    shard.
+    """The jax-model engine: EngineCore + the compiled prefill and batched
+    decode. Single-runtime deployments use this directly; the sharded
+    router drives one model engine (or simulated core) per shard.
 
     The weights are held in ``cfg.dtype`` (cast once here, so the per-call
-    cast in ``forward`` is a no-op) and reach the jitted decode as an
+    cast in ``forward`` is a no-op) and reach each jitted program as an
     argument: a closed-over array would be baked into the program as a
-    constant. A step whose logits are not finite raises
+    constant. Both programs take the batched cache donated and return it
+    updated in place. A step whose logits are not finite raises
     ``FloatingPointError``, which cancels the engine and surfaces from
-    ``stop()``."""
+    ``stop()``.
+
+    A prefill is one program per prompt-length bucket (``prefill_bucket``):
+    the forward, the first token from the last real position's logits
+    alone, and the splice of the prompt's cache into its slot. A dense
+    model's prompt is padded at its end to the bucket: attention is
+    causal, so no real position sees a pad, and decode overwrites the pad
+    rows before its mask (``k <= pos``) reaches them. Other families
+    compile at the exact length: pads would run through an SSM's state
+    and change an MoE layer's capacity."""
 
     def __init__(self, cfg, params, runtime, *, n_slots: int = 4,
                  max_seq: int = 256, sharder=NULL_SHARDER, greedy=True,
@@ -547,46 +557,32 @@ class ServeEngine(EngineCore):
         self._decode_fn = jax.jit(
             functools.partial(_decode_batch, cfg, sharder),
             donate_argnums=(1,))
-
-    # ---------------------------------------------------------- model ops
-    def _prefill_one(self, tokens: np.ndarray):
-        """Single-sequence prefill -> (first_token, cache_slices)."""
-        import jax.numpy as jnp
-
-        from repro.models import api as mapi
-        tracer = self.rt.tracer
-        batch = {"tokens": jnp.asarray(tokens)[None, :]}
-        with tracer.span("serve.prefill.forward"):
-            logits, _, cache = mapi.forward(self.cfg, self.params, batch,
-                                            self.sh, mode="prefill")
-        with tracer.span("serve.prefill.sync"):
-            last = logits[0, -1]
-            if not bool(jnp.all(jnp.isfinite(last))):
-                raise FloatingPointError("prefill produced non-finite logits")
-            first = int(jnp.argmax(last))
-        return first, cache
+        self._prefill_fn = jax.jit(
+            functools.partial(_prefill_splice, cfg, sharder, n_slots),
+            donate_argnums=(1,))
+        self._prefill_buckets: set = set()
+        self.stats["prefill_programs"] = 0
 
     # ---------------------------------------------------------- core hooks
     def _prefill_exec(self, req: Request, slot: int) -> int:
         import jax
+        tracer = self.rt.tracer
         L = min(len(req.prompt), self.max_seq - req.max_new_tokens - 1)
-        first, cache = self._prefill_one(req.prompt[:L])
-
-        # splice the sequence cache into the batched slot
-        def splice(dst, src):
-            if dst is None:
-                return None
-            if dst.ndim >= 3 and src.shape[0] == dst.shape[0] and \
-                    dst.shape[1] == self.n_slots:
-                # (L, n_slots, T, ...) <- (L, 1, S, ...)
-                return jax.lax.dynamic_update_slice(
-                    dst, src.astype(dst.dtype),
-                    (0, slot) + (0,) * (dst.ndim - 2))
-            return dst
-        with self.rt.tracer.span("serve.prefill.splice"):
-            self.cache = jax.tree_util.tree_map(splice, self.cache, cache)
+        S = prefill_bucket(self.cfg, L, self.max_seq)
+        tokens = np.zeros((1, S), np.int32)
+        tokens[0, :L] = req.prompt[:L]
+        tracer.event("serve.prefill.pad", S - L)
+        self._prefill_buckets.add(S)  # jit builds a program per shape
+        self.stats["prefill_programs"] = len(self._prefill_buckets)
+        with tracer.span("serve.prefill.forward"):
+            first, finite, self.cache = self._prefill_fn(
+                self.params, self.cache, tokens, np.int32(L), np.int32(slot))
+        with tracer.span("serve.prefill.sync"):
+            first, finite = jax.device_get((first, finite))
+            if not finite:
+                raise FloatingPointError("prefill produced non-finite logits")
         self.pos[slot] = L
-        return first
+        return int(first)
 
     def _decode_exec(self, live: list) -> np.ndarray:
         import jax.numpy as jnp
@@ -622,3 +618,44 @@ def _decode_batch(cfg, sh, params, cache, tokens, pos):
     last = logits[:, -1, :]
     return (jnp.argmax(last, axis=-1), jnp.all(jnp.isfinite(last), axis=-1),
             new_cache)
+
+
+PREFILL_BUCKET = 256  # a dense prompt is padded up to a multiple of this
+
+
+def prefill_bucket(cfg, length: int, max_seq: int) -> int:
+    """The padded length a prompt of ``length`` tokens is prefilled at: the
+    next multiple of PREFILL_BUCKET, at most ``max_seq``, for a dense
+    model; the exact length for every other family."""
+    if cfg.family != "dense":
+        return length
+    return min(-(-length // PREFILL_BUCKET) * PREFILL_BUCKET, max_seq)
+
+
+def _prefill_splice(cfg, sh, n_slots, params, cache, tokens, length, slot):
+    """Prefill one prompt, tokens (1, bucket) with ``length`` real tokens,
+    into slot ``slot`` of the batched cache -> (greedy first token, its
+    logits finite, cache). The final norm and LM head run on position
+    ``length - 1`` alone. Each leaf with a slot axis (ndim >= 3, axis 1 of
+    size n_slots) takes the prompt's rows at (0, slot, 0, ...); the rest
+    pass through. Module-level so that jit sees every array as an
+    argument."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models import api as mapi
+    logits, _, seq = mapi.forward(cfg, params, {"tokens": tokens}, sh,
+                                  mode="prefill", logits_at=length - 1)
+
+    def splice(dst, src):
+        if dst.ndim >= 3 and src.shape[0] == dst.shape[0] and \
+                dst.shape[1] == n_slots:
+            # (L, n_slots, T, ...) <- (L, 1, S, ...)
+            return jax.lax.dynamic_update_slice(
+                dst, src.astype(dst.dtype),
+                (0, slot) + (0,) * (dst.ndim - 2))
+        return dst
+
+    last = logits[0, -1]
+    return (jnp.argmax(last), jnp.all(jnp.isfinite(last)),
+            jax.tree_util.tree_map(splice, cache, seq))

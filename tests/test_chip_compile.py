@@ -25,7 +25,7 @@ from repro.launch.steps import TrainConfig, make_prefill_step
 from repro.models import api as mapi
 from repro.models.common import NULL_SHARDER
 from repro.models.params import abstract_params
-from repro.serve.engine import _decode_batch
+from repro.serve.engine import _decode_batch, _prefill_splice
 
 # HBM the v5e compiler lets one program use
 V5E_HBM_BYTES = int(15.75 * 2**30)
@@ -181,3 +181,43 @@ def test_starcoder2_cache_has_no_tile_padding(starcoder2_decode):
 def test_starcoder2_prefill_fits_one_v5e(one_chip):
     """The code-completion mix's longest prompt, 3584 tokens."""
     _fits(_prefill_step("starcoder2-3b", 3584, one_chip))
+
+
+def _serve_prefill(name, n_slots, max_seq, bucket, one_chip):
+    """The serve engine's prefill program at one length bucket, full
+    width, the cache donated, compiled for one v5e."""
+    cfg = get_config(name)
+    cache = _on(one_chip, mapi.abstract_cache(cfg, n_slots, max_seq))
+
+    def i32(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    step = jax.jit(functools.partial(_prefill_splice, cfg, NULL_SHARDER,
+                                     n_slots), donate_argnums=(1,))
+    compiled = step.lower(_serving_params(cfg, one_chip), cache,
+                          i32((1, bucket)), i32(()), i32(())).compile()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(cache))
+    return cfg, compiled, cache_bytes
+
+
+@pytest.mark.parametrize("name,n_slots,stack", [
+    ("qwen3-1.7b", 8, "28,8,4096,8,128"),
+    ("starcoder2-3b", 16, "30,16,4096,2,128")])
+def test_serve_prefill_splices_in_place_and_emits_one_row(
+        one_chip, name, n_slots, stack):
+    """At the code mix's longest bucket, 3584: the program fits, writes
+    the prompt's rows into the donated cache in place (the whole cache
+    aliased, no copy of a K or V stack) and computes the logits of one
+    position, never float32 logits at every position."""
+    cfg, compiled, cache_bytes = _serve_prefill(name, n_slots, 4096, 3584,
+                                                one_chip)
+    _fits(compiled)
+    assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+    text = compiled.as_text()
+    stack_ops = re.findall(re.escape(f"= bf16[{stack}]") +
+                           r"\{[^}]*\} ([\w-]+)\(", text)
+    assert stack_ops, "no whole-stack op found: the pattern is stale"
+    assert "copy" not in stack_ops, stack_ops
+    shapes = {tuple(map(int, d.split(",")))
+              for d in re.findall(r"\w+\[([\d,]+)\]", text)}
+    assert not [d for d in shapes if {3584, cfg.vocab_size} <= set(d)]

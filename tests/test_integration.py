@@ -132,7 +132,7 @@ def test_serving_error_cancel_releases_waiters():
     params = init_params(cfg, jax.random.PRNGKey(0))
     rt = TaskRuntime(n_workers=2).start()
     eng = ServeEngine(cfg, params, rt, n_slots=2, max_seq=32)
-    eng._prefill_one = lambda tokens: (_ for _ in ()).throw(
+    eng._prefill_fn = lambda *args: (_ for _ in ()).throw(
         RuntimeError("injected prefill failure"))
     eng.start()
     req = eng.submit(np.arange(4), max_new_tokens=4)

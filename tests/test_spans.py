@@ -17,8 +17,7 @@ from repro.launch.train import TrainEngine
 from repro.models import init_params
 from repro.serve import ServeEngine, SimEngine
 
-CHILDREN = {"serve.prefill": ("serve.prefill.forward", "serve.prefill.sync",
-                              "serve.prefill.splice"),
+CHILDREN = {"serve.prefill": ("serve.prefill.forward", "serve.prefill.sync"),
             "serve.decode": ("serve.decode.inputs", "serve.decode.launch",
                              "serve.decode.sync")}
 
